@@ -44,9 +44,8 @@ import json
 import multiprocessing as mp
 import os
 import time
+from concurrent.futures import ProcessPoolExecutor
 from typing import Dict, List
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from repro.api import ReuseSession, flow
 
@@ -112,7 +111,31 @@ def _bench_session(session: ReuseSession, dags, steps: int, windows: int = 5):
     return 1e3 * best
 
 
-def _measure_regime(dags, base_batch: int, steps: int, workers: int) -> Dict[str, float]:
+def _measure_plane(kw: Dict, chains: int, depth: int, base_batch: int,
+                   steps: int):
+    dags = _chains(chains, depth)
+    session = ReuseSession(
+        strategy="signature", execute=True, base_batch=base_batch, **kw
+    )
+    ms = _bench_session(session, dags, steps)
+    counts = {
+        df.name: {s: v["count"] for s, v in session.sink_digests(df.name).items()}
+        for df in dags
+    }
+    session.close()
+    return ms, counts
+
+
+def _in_child(fn, *args):
+    """``fn(*args)`` in a fresh spawned interpreter. The parent never
+    touches JAX, so each plane's process (and the multiproc plane's
+    workers, one per chip) can take an accelerator of its own."""
+    with ProcessPoolExecutor(1, mp_context=mp.get_context("spawn")) as pool:
+        return pool.submit(fn, *args).result()
+
+
+def _measure_regime(chains: int, depth: int, base_batch: int, steps: int,
+                    workers: int) -> Dict[str, float]:
     planes = {
         "sync": dict(backend="inprocess", step_mode="sync"),
         "threads": dict(backend="sharded", step_mode="concurrent",
@@ -123,25 +146,20 @@ def _measure_regime(dags, base_batch: int, steps: int, workers: int) -> Dict[str
     ms: Dict[str, float] = {}
     counts: Dict[str, Dict] = {}
     for name, kw in planes.items():
-        session = ReuseSession(
-            strategy="signature", execute=True, base_batch=base_batch, **kw
+        ms[name], counts[name] = _in_child(
+            _measure_plane, kw, chains, depth, base_batch, steps
         )
-        ms[name] = _bench_session(session, dags, steps)
-        counts[name] = {
-            df.name: {s: v["count"] for s, v in session.sink_digests(df.name).items()}
-            for df in dags
-        }
-        session.close()
         print(f"  {name:10s}: {ms[name]:8.2f} ms/step")
     for name in ("threads", "multiproc"):
         assert counts[name] == counts["sync"], f"{name} diverged from sync sink counts"
     return ms
 
 
-def _dryrun_roofline(dags, base_batch: int) -> Dict[str, float]:
+def _dryrun_roofline(chains: int, depth: int, base_batch: int) -> Dict[str, float]:
     """Makespan model of the deployment, calibrated from a short jit run."""
     from repro.ops.costs import fit_latency_model
 
+    dags = _chains(chains, depth)
     cal = ReuseSession(strategy="signature", execute=True, backend="inprocess",
                        base_batch=base_batch, step_mode="sync")
     for df in dags:
@@ -177,17 +195,18 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     workers = args.workers or (os.cpu_count() or 2)
-    dags = _chains(args.chains, args.depth)
 
     ceiling = host_parallel_ceiling(workers)
     print(f"host: {os.cpu_count()} cpus, effective parallel ceiling ×{ceiling:.2f} "
           f"for {workers} processes")
 
     print(f"dispatch-bound regime (batch {args.dispatch_batch}):")
-    disp = _measure_regime(dags, args.dispatch_batch, args.steps, workers)
+    disp = _measure_regime(args.chains, args.depth, args.dispatch_batch,
+                           args.steps, workers)
     print(f"compute-bound regime (batch {args.compute_batch}):")
-    comp = _measure_regime(dags, args.compute_batch, args.steps, workers)
-    dry = _dryrun_roofline(dags, args.compute_batch)
+    comp = _measure_regime(args.chains, args.depth, args.compute_batch,
+                           args.steps, workers)
+    dry = _in_child(_dryrun_roofline, args.chains, args.depth, args.compute_batch)
 
     record = {
         "bench": "distributed_data_plane",

@@ -34,8 +34,6 @@ import os
 import time
 from typing import Any, Dict, List
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import numpy as np
 
 try:  # package (python -m benchmarks.run) vs script (python benchmarks/foo.py)

@@ -34,8 +34,6 @@ import os
 import time
 from typing import Any, Dict, List
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 try:  # package (python -m benchmarks.run) vs script (python benchmarks/foo.py)
     from benchmarks._host import stamp
 except ImportError:  # pragma: no cover - script execution path

@@ -20,9 +20,18 @@ span hosts:
 Both return a :class:`WorkerHandle`: the command connection plus the
 process-lifecycle surface (``is_alive`` / ``terminate`` / ``join``) the
 supervisor needs for crash detection and forced respawns.
+
+A TPU chip belongs to one process at a time, and a process that
+initializes JAX's TPU backend takes every chip on its host. So a local
+jit-plane worker is pinned to chip ``worker_id`` through libtpu's
+environment (:func:`jit_worker_env`), and the launch is refused when the
+worker would have no chip of its own or the launching process holds the
+TPU itself. Where JAX is kept off the TPU (``JAX_PLATFORMS`` without
+``tpu``) or the host has none, workers inherit the environment unchanged.
 """
 from __future__ import annotations
 
+import glob
 import os
 import secrets
 import select
@@ -33,6 +42,66 @@ import threading
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 from repro.runtime.transport import _recv_msg, _send_msg
+
+
+_TPU_PROCESS_PORT_BASE = 8476  # libtpu's default; worker i takes base + i
+
+
+def local_tpu_chips(dev_root: str = "/dev") -> int:
+    """Number of TPU chips this process may open: libtpu's device nodes,
+    ``/dev/accel<n>`` (v4, v5p) or ``/dev/vfio/<n>`` (v5e, v6e), counted
+    without importing JAX. PCI ids would overcount: a container handed one
+    chip of a four-chip host still sees all four in sysfs."""
+    return len(glob.glob(os.path.join(dev_root, "accel[0-9]*"))
+               + glob.glob(os.path.join(dev_root, "vfio", "[0-9]*")))
+
+
+def _holds_tpu() -> bool:
+    """Whether this process has initialized JAX's TPU backend."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized() and "tpu" in xla_bridge.backends()
+
+
+def jit_worker_env(worker_id: int) -> Dict[str, str]:
+    """Environment overrides that give jit worker ``worker_id`` TPU chip
+    ``worker_id`` and no other (``{}`` where no TPU is in play).
+
+    Raises :class:`RuntimeError` instead of starting a worker that would
+    hang on libtpu's lock: when this process holds the TPU, or when the
+    pool would outnumber the host's chips."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.lower().split(","):
+        return {}
+    chips = local_tpu_chips()
+    if chips == 0:
+        return {}
+    if _holds_tpu():
+        raise RuntimeError(
+            "this process holds the TPU, so a multiproc jit worker could take "
+            "no chip: start the multiproc backend from a process that has not "
+            "initialized JAX, or step in-process (backend='inprocess' or 'sharded')"
+        )
+    if worker_id >= chips:
+        raise RuntimeError(
+            f"jit worker {worker_id} would have no TPU chip of its own: this "
+            f"host has {chips}, so the multiproc jit plane takes at most "
+            f"{chips} workers"
+        )
+    port = _TPU_PROCESS_PORT_BASE + worker_id
+    # JAX's own recipe for one chip per process (jax._src.test_multiprocess):
+    # one-chip bounds, a libtpu port each, and libtpu's one-process-per-host
+    # lock lifted, since the chips are already split between the workers
+    return {
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+        "TPU_VISIBLE_CHIPS": str(worker_id),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(port),
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+    }
 
 
 class WorkerHandle:
@@ -149,10 +218,11 @@ class LocalProcessLauncher:
                plane: str, log_path: str) -> WorkerHandle:
         from repro.runtime.worker import _worker_main
 
+        env = jit_worker_env(worker_id) if plane == "jit" else {}
         parent_conn, child_conn = self._ctx.Pipe()
         proc = self._ctx.Process(
             target=_worker_main,
-            args=(child_conn, worker_id, transport_spec, plane, log_path),
+            args=(child_conn, worker_id, transport_spec, plane, log_path, env),
             name=f"repro-worker-{worker_id}",
             daemon=True,
         )
@@ -214,6 +284,8 @@ class SubprocessLauncher:
             )
             src = os.path.dirname(os.path.abspath(pkg_dir))
             env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+            if plane == "jit":
+                env.update(jit_worker_env(worker_id))
         proc = subprocess.Popen(cmd, env=env)
         server.settimeout(self.accept_timeout)
         try:

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams, MemorySpace
-
 NEG_INF = -1e30
 _LANES = 128
 
@@ -112,7 +110,7 @@ def decode_attention(
         kernel,
         grid=(b, kv, ns),
         in_specs=[
-            pl.BlockSpec(memory_space=MemorySpace.SMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
             pl.BlockSpec((1, groups, 1, hd), lambda bi, ki, si: (bi, 0, ki, 0)),
             pl.BlockSpec((1, bs, 1, hd), lambda bi, ki, si: (bi, si, ki, 0)),
             pl.BlockSpec((1, bs, 1, hd), lambda bi, ki, si: (bi, si, ki, 0)),
@@ -124,7 +122,7 @@ def decode_attention(
             pltpu.VMEM((groups, _LANES), jnp.float32),
             pltpu.VMEM((groups, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 _LANES = 128
 
@@ -145,7 +143,7 @@ def flash_attention(
             pltpu.VMEM((bq, _LANES), jnp.float32),  # l
             pltpu.VMEM((bq, hd), jnp.float32),      # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,19 +1,12 @@
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("REPRO_EXTRA_XLA_FLAGS", "")
-).strip()
-
 """Dry-runs: model-compile cells and dataflow-trace simulations.
 
 Mode 1 (model cells) — ``lower() + compile()`` every (architecture ×
 input-shape × mesh) cell on placeholder devices, and extract the roofline
-terms from the compiled artifact.
-
-The two lines above MUST stay first — jax locks the device count on first
-init. Run one cell per process (the CLI default) so device state and
-compile memory stay isolated:
+terms from the compiled artifact. ``main`` appends
+``--xla_force_host_platform_device_count=512`` to ``XLA_FLAGS`` for this
+mode only, before JAX is imported (JAX locks the device count on first
+init); flags the caller set are kept. Run one cell per process (the CLI
+default) so device state and compile memory stay isolated:
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch granite-20b \
         --shape train_4k [--multi-pod] [--json out.json]
@@ -47,7 +40,7 @@ the recovery tests simulate the crash):
 """
 import argparse
 import json
-import re
+import os
 import subprocess
 import sys
 import time
@@ -103,8 +96,6 @@ def run_cell(
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older JAX wraps the dict in a list
-        cost = cost[0] if cost else {}
     print(f"memory_analysis: {mem}")
     print(
         "cost_analysis: flops=%.4g bytes=%.4g"
@@ -328,6 +319,15 @@ def _parse_autoscale(spec: Optional[str]) -> Optional[Dict[str, Any]]:
         raise SystemExit(f"--autoscale wants MIN:MAX (e.g. 1:4), got {spec!r}") from None
 
 
+def _force_host_devices(n: int) -> None:
+    """Give the model-cell modes ``n`` placeholder host devices (read when
+    JAX initializes its backends, so this must run before that)."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    flags = os.environ.get("XLA_FLAGS", "")
+    if flag not in flags.split():
+        os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
@@ -450,6 +450,7 @@ def main(argv=None) -> int:
                 json.dump(rec, f, indent=1)
         return 0
 
+    _force_host_devices(512)
     if args.all:
         return sweep(args)
 

@@ -18,12 +18,20 @@ from __future__ import annotations
 from typing import Dict
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules constrain
+    activations through ``with_sharding_constraint``, which refuses the
+    Explicit axes ``jax.make_mesh`` gives by default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def mesh_sizes(mesh) -> Dict[str, int]:
@@ -32,4 +40,4 @@ def mesh_sizes(mesh) -> Dict[str, int]:
 
 def make_host_mesh():
     """1-device mesh for CPU smoke paths (axes exist, sizes 1)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))

@@ -12,31 +12,6 @@ import jax
 import jax.numpy as jnp
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """``jax.shard_map`` across JAX releases.
-
-    Newer JAX exposes it at the top level with ``axis_names``/``check_vma``;
-    older releases ship ``jax.experimental.shard_map.shard_map`` whose
-    equivalents are ``auto`` (the complement of the manual axes) and
-    ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, **kwargs)
-
-
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     dt = x.dtype
     x = x.astype(jnp.float32)

@@ -27,7 +27,7 @@ from .attention import (
     gqa_project_qkv,
     mla_decode,
 )
-from .common import KeyGen, apply_norm, apply_rope, rms_norm, shard_map_compat
+from .common import KeyGen, apply_norm, apply_rope, rms_norm
 from .config import ModelConfig
 from .mlp import mlp, moe_layer
 from .ssm import _causal_conv as mamba_conv
@@ -861,7 +861,7 @@ def decode_step_pp(
         P(stage_axis, None),
         jax.tree.map(lambda _: P(stage_axis), cache["layers"]),
     )
-    h_next, logits, new_layers = shard_map_compat(
+    h_next, logits, new_layers = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=in_specs,

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from . import sharding
-from .common import dense_init, shard_map_compat
+from .common import dense_init
 
 
 def act_fn(name: str):
@@ -280,7 +280,7 @@ def moe_layer_ep(p: Dict[str, Any], x: jnp.ndarray, cfg, rules) -> jnp.ndarray:
             full[i] = a
         return body(*full)
 
-    return shard_map_compat(
+    return jax.shard_map(
         wrapper,
         mesh=mesh,
         in_specs=f_specs,

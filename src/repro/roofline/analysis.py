@@ -132,8 +132,6 @@ def analyze_compiled(compiled, cfg, cell, mesh) -> Dict[str, Any]:
 
     chips = mesh.devices.size
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older JAX wraps the dict in a list
-        cost = cost[0] if cost else {}
     # XLA's cost_analysis counts while bodies once — recorded for reference
     raw_flops = float(cost.get("flops", 0.0))
     raw_bytes = float(cost.get("bytes accessed", 0.0))

@@ -37,6 +37,12 @@ process-local cache (:func:`process_compile_cache`) surfaced through the
 ``cache_stats`` worker RPC. Hit/miss/evict counters flow up to
 ``session.stats()``.
 
+The in-memory cache lives as long as its process. Across processes and
+runs, :func:`enable_persistent_cache` turns on JAX's persistent
+compilation cache for the jit planes (the in-process backends' constructor
+and each jit worker's startup), so a fresh process loads the segment
+programs an earlier run compiled instead of compiling them again.
+
 This module is import-safe without JAX (the coordinator of the multiproc
 backend is JAX-free); :func:`~repro.runtime.segment.build_segment` is
 imported lazily at first miss.
@@ -44,6 +50,7 @@ imported lazily at first miss.
 from __future__ import annotations
 
 from collections import OrderedDict
+from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.graph import Dataflow, Task
@@ -54,9 +61,14 @@ from .broker import topic_for
 
 __all__ = [
     "CompileCache",
+    "enable_persistent_cache",
+    "persistent_cache_dir",
     "process_compile_cache",
     "structural_signature",
 ]
+
+# src/repro/runtime/compile_cache.py -> the checkout's root
+_CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _canonical_maps(spec: SegmentSpec) -> Tuple[Dict[str, str], Dict[str, str]]:
@@ -245,3 +257,35 @@ def process_compile_cache() -> CompileCache:
     if _PROCESS_CACHE is None:
         _PROCESS_CACHE = CompileCache()
     return _PROCESS_CACHE
+
+
+def persistent_cache_dir() -> str:
+    """Where compiled programs persist: JAX's own
+    ``jax_compilation_cache_dir`` (set from ``$JAX_COMPILATION_CACHE_DIR``
+    or by the embedding application) when it is set, else ``.jax_cache``
+    at the root of the checkout. The default is a fixed path, never a
+    temporary, per-pid or timed one: a cache directory that moves between
+    runs is never hit."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or str(_CHECKOUT_CACHE_DIR)
+
+
+def enable_persistent_cache() -> Optional[str]:
+    """Point JAX's persistent compilation cache at
+    :func:`persistent_cache_dir` and write every program to it; returns
+    the directory, or ``None`` when the cache is switched off
+    (``JAX_ENABLE_COMPILATION_CACHE=false``, as the test suite runs).
+
+    Segment programs compile in well under JAX's default one-second
+    write threshold, so the threshold drops to zero: otherwise most of
+    them would never be written and every new process would compile
+    them again."""
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    path = persistent_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

@@ -78,9 +78,11 @@ class InProcessJitBackend(ExecutionBackend):
         self.broker = self.transport  # backwards-compatible alias
         # Compiled-segment reuse: structurally identical segments share one
         # canonical jitted executable instead of recompiling (coordinator-
-        # side — this backend compiles in-process).
-        from .compile_cache import CompileCache
+        # side — this backend compiles in-process); across processes the
+        # persistent cache keeps what earlier runs compiled.
+        from .compile_cache import CompileCache, enable_persistent_cache
 
+        enable_persistent_cache()
         self.compile_cache = CompileCache()
         self.compile_cache.tracer = self.tracer
         # Per-topic sequence targets for the concurrent step in flight

@@ -250,7 +250,8 @@ def _filename_topic(name: str) -> str:
 class _ShmTopic:
     """One attached topic file: mmap + parsed geometry."""
 
-    __slots__ = ("mm", "file", "nslots", "slot_bytes", "path", "ino")
+    __slots__ = ("mm", "file", "nslots", "slot_bytes", "path", "ino",
+                 "seq_word", "dropped_word", "bytes_word")
 
     def __init__(self, path: str, file, mm: mmap.mmap, ino: int):
         self.path = path
@@ -264,18 +265,27 @@ class _ShmTopic:
             raise TransportError(f"shm topic file {path!r} has a bad header")
         self.nslots = nslots
         self.slot_bytes = slot_bytes
+        # After creation the live header fields are read and written only
+        # as single aligned words. struct.pack_into zeroes its whole target
+        # before packing and writes byte by byte, so a reader racing a
+        # header rewrite could see sequence 0 (or 0 while 255 becomes 256).
+        self.seq_word = memoryview(mm)[8:16].cast("Q")
+        self.dropped_word = memoryview(mm)[16:20].cast("I")
+        self.bytes_word = memoryview(mm)[32:40].cast("Q")
 
     def read_seq(self) -> int:
-        return _HDR.unpack_from(self.mm, 0)[2]
+        return self.seq_word[0]
 
     def read_dropped(self) -> bool:
-        return bool(_HDR.unpack_from(self.mm, 0)[3])
+        return bool(self.dropped_word[0])
 
     def slot_offset(self, publish_no: int) -> int:
         idx = (publish_no - 1) % self.nslots
         return _HDR_SIZE + idx * (_SLOT_HDR_SIZE + self.slot_bytes)
 
     def close(self) -> None:
+        for word in (self.seq_word, self.dropped_word, self.bytes_word):
+            word.release()
         try:
             self.mm.close()
         except BufferError:
@@ -424,10 +434,9 @@ class ShmTransport(Transport):
             shape[0], shape[1], shape[2], shape[3], len(payload),
         )
         st.mm[off + _SLOT_HDR_SIZE: off + _SLOT_HDR_SIZE + len(payload)] = payload
-        # publish point: bump seq (and the single-writer byte counter) last
-        _, _, _, dropped, nslots, slot_bytes, tb = _HDR.unpack_from(st.mm, 0)
-        _HDR.pack_into(st.mm, 0, _SHM_MAGIC, _SHM_VERSION, seq + 1, 0,
-                       nslots, slot_bytes, tb + len(payload))
+        # publish point: the single-writer byte counter, then seq last
+        st.bytes_word[0] += len(payload)
+        st.seq_word[0] = seq + 1
 
     def _read_latest(
         self, st: _ShmTopic, topic: str, copy: bool = False
@@ -556,13 +565,11 @@ class ShmTransport(Transport):
             # fold the topic's cumulative totals into the graveyard, mark
             # dropped (wakes synced fetches in every attached process),
             # then unlink the incarnation
-            _, _, seq, _, nslots, slot_bytes, tb = _HDR.unpack_from(st.mm, 0)
             meta = self._read_meta()
-            meta["graveyard_bytes"] += int(tb)
-            meta["graveyard_publishes"] += int(seq)
+            meta["graveyard_bytes"] += st.bytes_word[0]
+            meta["graveyard_publishes"] += st.read_seq()
             self._write_meta(meta)
-            _HDR.pack_into(st.mm, 0, _SHM_MAGIC, _SHM_VERSION, seq, 1,
-                           nslots, slot_bytes, tb)
+            st.dropped_word[0] = 1
             try:
                 os.remove(st.path)
             except FileNotFoundError:  # pragma: no cover - concurrent drop
@@ -620,9 +627,8 @@ class ShmTransport(Transport):
             st = self._attach(topic)
             if st is None:
                 continue
-            _, _, seq, _, _, _, tb = _HDR.unpack_from(st.mm, 0)
-            total_b += int(tb)
-            total_p += int(seq)
+            total_b += st.bytes_word[0]
+            total_p += st.read_seq()
         return {
             "bytes_published": total_b - meta["base_bytes"],
             "publishes": total_p - meta["base_publishes"],

@@ -373,11 +373,23 @@ class _SpillWriter:
 
 
 def _worker_main(conn, worker_id: int, transport_spec: Dict[str, Any],
-                 plane: str, log_path: str) -> None:
-    """The worker loop: blocking command RPCs against owned segments."""
+                 plane: str, log_path: str,
+                 env: Optional[Dict[str, str]] = None) -> None:
+    """The worker loop: blocking command RPCs against owned segments.
+
+    ``env`` is applied before JAX initializes in this process — the
+    launcher's chip pinning (:func:`repro.cluster.launcher.jit_worker_env`)."""
+    os.environ.update(env or {})
     log = _WorkerLog(log_path, worker_id)
     log.write("start", pid=os.getpid(), plane=plane,
               transport=transport_spec.get("kind"))
+    if plane == "jit":
+        import jax
+
+        from .compile_cache import enable_persistent_cache
+
+        enable_persistent_cache()
+        log.write("devices", devices=[str(d) for d in jax.devices()])
     transport = connect_transport(transport_spec)
     # telemetry plane: the per-process registry/tracer the coordinator
     # pulls over the "metrics" op (tracer stays disabled until an "obs"

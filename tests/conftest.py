@@ -11,6 +11,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # Keep the default 1-device view for smoke tests and benches. The multi-pod
 # dry-run (launch/dryrun.py) sets XLA_FLAGS itself in a fresh process.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# The jit planes turn on JAX's persistent compilation cache inside the
+# checkout; tests (and the worker processes they start) write none of it.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 
 @pytest.fixture

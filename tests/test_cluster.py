@@ -502,3 +502,58 @@ class TestSubprocessLauncher:
         assert {n: {s: d["count"] for s, d in v.items()} for n, v in got.items()} == {
             n: {s: d["count"] for s, d in v.items()} for n, v in ref.items()
         }
+
+
+# -- one process per TPU chip ---------------------------------------------------
+
+
+class TestJitWorkerChipPinning:
+    """The environment a local launcher gives each jit-plane worker."""
+
+    @pytest.fixture
+    def tpu_host(self, monkeypatch):
+        from repro.cluster import launcher
+
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(launcher, "local_tpu_chips", lambda: 4)
+        monkeypatch.setattr(launcher, "_holds_tpu", lambda: False)
+        return launcher
+
+    def test_worker_i_gets_chip_i(self, tpu_host):
+        env = tpu_host.jit_worker_env(2)
+        assert env["TPU_VISIBLE_CHIPS"] == "2"
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert env["ALLOW_MULTIPLE_LIBTPU_LOAD"] == "1"
+        ports = {tpu_host.jit_worker_env(i)["TPU_PROCESS_PORT"] for i in range(4)}
+        assert len(ports) == 4
+
+    def test_more_workers_than_chips_refused(self, tpu_host):
+        with pytest.raises(RuntimeError, match="at most 4 workers"):
+            tpu_host.jit_worker_env(4)
+
+    def test_parent_holding_the_tpu_refused_before_spawning(self, tpu_host, monkeypatch, tmp_path):
+        monkeypatch.setattr(tpu_host, "_holds_tpu", lambda: True)
+        with pytest.raises(RuntimeError, match="holds the TPU"):
+            tpu_host.LocalProcessLauncher().launch(
+                0, {"kind": "inproc"}, "jit", str(tmp_path / "w.log")
+            )
+
+    def test_cpu_platform_and_chipless_host_leave_env_alone(self, tpu_host, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert tpu_host.jit_worker_env(7) == {}
+        monkeypatch.delenv("JAX_PLATFORMS")
+        monkeypatch.setattr(tpu_host, "local_tpu_chips", lambda: 0)
+        assert tpu_host.jit_worker_env(7) == {}
+
+    def test_chips_counted_from_device_nodes(self, tmp_path):
+        from repro.cluster.launcher import local_tpu_chips
+
+        assert local_tpu_chips(str(tmp_path)) == 0
+        (tmp_path / "vfio").mkdir()
+        for name in ("vfio/3", "vfio/vfio", "tty0"):  # one v5e chip, no others
+            (tmp_path / name).write_text("")
+        assert local_tpu_chips(str(tmp_path)) == 1
+        for name in ("accel0", "accel1"):  # v4 / v5p nodes
+            (tmp_path / name).write_text("")
+        assert local_tpu_chips(str(tmp_path)) == 3

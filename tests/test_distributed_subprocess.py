@@ -87,7 +87,8 @@ batch = {
 }
 _, m_ref = jax.jit(step)(jax.tree.map(lambda x: x, state), batch)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 2), ("data", "model"))
 rules = shd.AxisRules({"data": 2, "model": 2}); rules.mesh = mesh
 pspecs = shd.infer_param_specs(state["params"], rules)
 sspecs = {"step": P(), "params": pspecs, "mu": pspecs, "nu": pspecs}
@@ -105,11 +106,6 @@ print("OK", float(m["loss"]))
     assert "OK" in r.stdout
 
 
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="partial-auto shard_map needs newer JAX; this XLA build rejects it "
-    "(UNIMPLEMENTED: PartitionId under SPMD partitioning)",
-)
 def test_pipeline_parallel_decode_runs():
     """PP decode (shard_map manual-data/auto-model) compiles and runs a
     steady-state round on a 2×2 mesh; logits finite, cache len advances."""

@@ -577,3 +577,68 @@ class TestFusedKernelDigestIdentity:
 
     def test_fused_equals_unfused(self):
         assert self._run(True) == self._run(False)
+
+
+# -- persistent compilation cache ------------------------------------------------
+
+
+@pytest.fixture
+def jax_cache_dir_config():
+    """Restores JAX's compilation-cache directory setting after the test."""
+    import jax
+
+    saved = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", saved)
+
+
+class TestPersistentCachePath:
+    def test_env_directory_wins(self, tmp_path):
+        import os
+        import subprocess
+        import sys
+
+        src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path), PYTHONPATH=src)
+        out = subprocess.run(
+            [sys.executable, "-c", "from repro.runtime.compile_cache import "
+             "persistent_cache_dir; print(persistent_cache_dir())"],
+            env=env, capture_output=True, text=True, check=True,
+        )
+        assert out.stdout.strip() == str(tmp_path)
+
+    def test_config_directory_wins(self, jax_cache_dir_config, tmp_path):
+        from repro.runtime.compile_cache import persistent_cache_dir
+
+        jax_cache_dir_config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert persistent_cache_dir() == str(tmp_path)
+
+    def test_default_is_fixed_inside_the_checkout(self, jax_cache_dir_config):
+        import os
+
+        from repro.runtime.compile_cache import persistent_cache_dir
+
+        jax_cache_dir_config.update("jax_compilation_cache_dir", None)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert persistent_cache_dir() == os.path.join(root, ".jax_cache")
+
+    def test_enable_points_jax_at_the_directory(self, jax_cache_dir_config, tmp_path):
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from repro.runtime.compile_cache import enable_persistent_cache
+
+        assert enable_persistent_cache() is None  # the suite runs with it off
+        keys = ("jax_enable_compilation_cache",
+                "jax_persistent_cache_min_compile_time_secs")
+        saved = {k: getattr(jax.config, k) for k in keys}
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        try:
+            jax.config.update("jax_enable_compilation_cache", True)
+            assert enable_persistent_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        finally:
+            for k, v in saved.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
