@@ -1,0 +1,77 @@
+"""Finds a cell's pieces by name: ``BENCHMARK.json`` names the pair
+(configuration, traffic); the configuration, its collection, the traffic mix,
+the correctness limits and each metric's reader sit in files of their own
+under ``bench/``, so a later cell, configuration or metric is added by adding
+files and entries, never by editing one that is there."""
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, List
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+
+
+def _json(*parts: str) -> Dict[str, Any]:
+    with open(os.path.join(BENCH, *parts)) as f:
+        return json.load(f)
+
+
+@dataclass
+class Cell:
+    name: str
+    chips: int
+    config: Dict[str, Any]
+    collection: List[Dict[str, Any]]
+    traffic: Dict[str, Any]
+    limits: Dict[str, Any]
+    end_to_end: List[Dict[str, Any]]
+    per_layer: List[Dict[str, Any]]
+
+
+def _applies(metric: Dict[str, Any], cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def load_cell(name: str, root: str = ROOT) -> Cell:
+    """The cell ``name`` as ``BENCHMARK.json`` names it."""
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    cells = {w["name"]: w for w in spec["workloads"]}
+    if name not in cells:
+        raise KeyError(f"no workload {name!r} in BENCHMARK.json (have {sorted(cells)})")
+    w = cells[name]
+    (entry,) = [c for c in spec["configs"] if c["name"] == w["config"]]
+    if os.path.normpath(entry["file"]) != os.path.join("bench", "configs", f"{w['config']}.json"):
+        raise ValueError(f"configuration {w['config']} is not at bench/configs/")
+    return make_cell(name, w["config"], w["traffic"], int(w["chips"]), spec)
+
+
+def make_cell(name: str, config_name: str, traffic: str, chips: int,
+              spec: Dict[str, Any]) -> Cell:
+    """A cell from its files: ``configs/<config_name>``, its collection,
+    ``traffic/<traffic>`` and ``limits/<name>``, with the metrics ``spec``
+    (``BENCHMARK.json``) gives it."""
+    config = _json("configs", f"{config_name}.json")
+    return Cell(
+        name=name,
+        chips=chips,
+        config=config,
+        collection=_json("collections", f"{config['collection']}.json")["dataflows"],
+        traffic=_json("traffic", f"{traffic}.json"),
+        limits=_json("limits", f"{name}.json"),
+        end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
+        per_layer=[m for m in spec["per_layer"] if _applies(m, name)],
+    )
+
+
+def reader(metric: str) -> Callable[[Any], Any]:
+    """``read(ctx)`` of ``bench/metrics/<metric>.py``."""
+    path = os.path.join(BENCH, "metrics", f"{metric}.py")
+    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod.read
