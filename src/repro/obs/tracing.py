@@ -14,6 +14,13 @@ ring buffer. Design constraints, in order:
   3. **Bounded** — the ring buffer (``capacity`` spans) drops oldest;
      ``sample_stride=N`` records every Nth span per span name, the knob
      that keeps per-segment tracing affordable at high step rates.
+  4. **On the profiler's clock too** — an enabled tracer with an
+     ``annotate`` factory (the jit backends install
+     ``jax.profiler.TraceAnnotation``) also opens a host annotation for
+     each recorded span, so a profiler trace names the program's phases
+     beside the device's ops. Annotation names come from a fixed
+     vocabulary: ``repro.segment`` (the segment's name as an argument)
+     for the ``segment`` category, ``repro.<name>`` for every other span.
 
 Span dicts are already Chrome trace-event shaped (``name``/``cat``/
 ``ph``/``ts``/``dur``/``pid``/``tid``/``args``), so export is just
@@ -30,7 +37,7 @@ import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Callable, ContextManager, Dict, Iterator, List, Optional
 
 __all__ = [
     "Tracer",
@@ -40,9 +47,10 @@ __all__ = [
 ]
 
 # Span categories used across the runtime (the README table's source):
-#   step        whole-step + wave structure        (backend.step)
+#   step        whole step, waves, account()       (backend.step)
 #   segment     per-segment step execution         (_step_named / workers)
 #   transport   input fetch / output publish       (executor)
+#   device      dispatch / wait on a segment step  (executor)
 #   rpc         coordinator→worker command RPCs    (multiproc _call)
 #   compile     compile-cache miss trace+jit       (compile_cache)
 #   control     submit / remove / preview / fuse   (manager, system)
@@ -62,6 +70,10 @@ class Tracer:
         self._seen: Dict[str, int] = {}
         self._lock = threading.Lock()
         self._pid = os.getpid()
+        # Optional host-annotation factory, ``annotate(name, **args)`` -> a
+        # context manager (``jax.profiler.TraceAnnotation``): each recorded
+        # span opens one for its duration. None keeps this module JAX-free.
+        self.annotate: Optional[Callable[..., ContextManager[Any]]] = None
 
     # -- configuration ------------------------------------------------------------
     def configure(
@@ -100,6 +112,13 @@ class Tracer:
         if not self.enabled or not self._admit(name):
             yield
             return
+        annotation = None
+        if self.annotate is not None:
+            if cat == "segment":
+                annotation = self.annotate("repro.segment", segment=name)
+            else:
+                annotation = self.annotate("repro." + name)
+            annotation.__enter__()
         t0 = time.monotonic_ns()
         try:
             yield
@@ -108,6 +127,8 @@ class Tracer:
             raise
         finally:
             t1 = time.monotonic_ns()
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             self._buf.append(
                 {
                     "name": name,
@@ -120,23 +141,6 @@ class Tracer:
                     "args": args,
                 }
             )
-
-    def instant(self, name: str, cat: str = "step", **args: Any) -> None:
-        """Record a zero-duration instant event (``ph: "i"``)."""
-        if not self.enabled:
-            return
-        self._buf.append(
-            {
-                "name": name,
-                "cat": cat,
-                "ph": "i",
-                "s": "t",
-                "ts": time.monotonic_ns() // 1000,
-                "pid": self._pid,
-                "tid": threading.get_ident() & 0xFFFFFFFF,
-                "args": args,
-            }
-        )
 
     # -- export -------------------------------------------------------------------
     def drain(self) -> List[Dict[str, Any]]:

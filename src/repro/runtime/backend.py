@@ -468,14 +468,19 @@ class ExecutionBackend:
         self._begin_concurrent_step()
         try:
             order = {n: s.spec.created_at for n, s in self.segments.items()}
-            with self.tracer.span(
-                "wave_dispatch", "step", step=self.step_count,
-                segments=len(self.segments),
-            ):
-                return run_ready_queue(
-                    self.seg_deps, self._step_named, self.max_workers, order,
-                    pool=self._pool, recover=self._step_recover,
-                )
+            if self.tracer.enabled:
+                with self.tracer.span(
+                    "wave_dispatch", "step", step=self.step_count,
+                    segments=len(self.segments),
+                ):
+                    return run_ready_queue(
+                        self.seg_deps, self._step_named, self.max_workers, order,
+                        pool=self._pool, recover=self._step_recover,
+                    )
+            return run_ready_queue(
+                self.seg_deps, self._step_named, self.max_workers, order,
+                pool=self._pool, recover=self._step_recover,
+            )
         finally:
             self._end_concurrent_step()
 
@@ -541,7 +546,11 @@ class ExecutionBackend:
             (max if concurrent else sum)([seg_ms[n] for n in wave if n in seg_ms] or [0.0])
             for wave in waves
         ]
-        live, paused_n, cost = self.account()
+        if self.tracer.enabled:
+            with self.tracer.span("account", "step"):
+                live, paused_n, cost = self.account()
+        else:
+            live, paused_n, cost = self.account()
         stragglers = self._update_stragglers(seg_ms)
         self.step_count += 1
         if self.on_wave is not None:

@@ -64,6 +64,7 @@ __all__ = [
     "enable_persistent_cache",
     "persistent_cache_dir",
     "process_compile_cache",
+    "program_name",
     "structural_signature",
 ]
 
@@ -115,6 +116,13 @@ def structural_signature(spec: SegmentSpec, dataflow: Dataflow) -> str:
             )
         )
     return _digest(parts)
+
+
+def program_name(signature: str) -> str:
+    """The jit name of a segment program: stable across runs and
+    processes for one structure (``jit_segment_<12 hex>`` in HLO and the
+    profiler's trace)."""
+    return f"segment_{signature[:12]}"
 
 
 def _canonicalize(

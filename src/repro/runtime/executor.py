@@ -85,6 +85,8 @@ class InProcessJitBackend(ExecutionBackend):
         enable_persistent_cache()
         self.compile_cache = CompileCache()
         self.compile_cache.tracer = self.tracer
+        # recorded spans also open host annotations on the profiler's clock
+        self.tracer.annotate = jax.profiler.TraceAnnotation
         # Per-topic sequence targets for the concurrent step in flight
         # (None outside one): each forwarding task publishes exactly once
         # per step, so a boundary read of this step must observe sequence
@@ -167,14 +169,22 @@ class InProcessJitBackend(ExecutionBackend):
                 inputs, tokens = self._gather_inputs(seg)
         else:
             inputs, tokens = self._gather_inputs(seg)
-        new_states, outputs = seg.step_fn(seg.states, seg.active, inputs)
+        if self.tracer.enabled:
+            with self.tracer.span("dispatch", "device", segment=seg.name):
+                new_states, outputs = seg.step_fn(seg.states, seg.active, inputs)
+        else:
+            new_states, outputs = seg.step_fn(seg.states, seg.active, inputs)
         if tokens:
             # Zero-copy stale-view check: the CPU jit may alias the host
             # views, so the computation must finish before we can trust it;
             # if any source slot lapped mid-step, recompute from private
             # copies and the untouched pre-step states. Publishes and the
             # state commit happen only after validation (exactly-once).
-            jax.block_until_ready((new_states, outputs))
+            if self.tracer.enabled:
+                with self.tracer.span("wait", "device", segment=seg.name):
+                    jax.block_until_ready((new_states, outputs))
+            else:
+                jax.block_until_ready((new_states, outputs))
             if not all(self.transport.view_valid(t, s) for t, s in tokens.items()):
                 for t in tokens:
                     inputs[t] = self.transport.fetch(t, copy=True)
@@ -195,7 +205,11 @@ class InProcessJitBackend(ExecutionBackend):
         # noise, and the sync/concurrent distinction evaporates. Blocking
         # here is what lets concurrent dispatch genuinely overlap devices:
         # each worker thread waits on *its* device while the others run.
-        jax.block_until_ready(new_states)
+        if self.tracer.enabled:
+            with self.tracer.span("wait", "device", segment=seg.name):
+                jax.block_until_ready(new_states)
+        else:
+            jax.block_until_ready(new_states)
         seg.steps_run += 1
         return None  # report measured wall-time
 

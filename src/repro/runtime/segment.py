@@ -33,6 +33,7 @@ from repro.ops import EVENT_WIDTH, Operator, operator_for_task
 
 from .backend import SegmentSpec, compute_batches  # noqa: F401 — canonical home
 from .broker import topic_for
+from .compile_cache import program_name, structural_signature
 
 PyTree = Any
 
@@ -147,6 +148,11 @@ def build_segment(
     the jitted step function is looked up by the spec's structural
     signature — a structurally identical segment built earlier shares its
     traced executable and this call skips XLA compilation entirely.
+
+    The program is named for its structure (``jit_segment_<signature>`` in
+    HLO and the profiler's trace) and each task's ops sit in a
+    ``jax.named_scope`` of its task type, so a device trace can be reduced
+    by segment structure and by task type.
     """
     operators: Dict[str, Operator] = {}
     for tid in spec.task_ids:
@@ -174,6 +180,7 @@ def build_segment(
     active = {tid: jnp.ones((), jnp.bool_) for tid in spec.task_ids}
 
     task_ids = list(spec.task_ids)
+    task_type = {t: dataflow.tasks[t].type for t in task_ids}
     parents = {t: list(spec.parents[t]) for t in task_ids}
     batch_of = dict(spec.batch_of)
     _peephole_fused_kernels(spec, dataflow, operators, parents)
@@ -186,42 +193,43 @@ def build_segment(
         outputs: Dict[str, jnp.ndarray] = {}  # task id -> output batch
         new_states: Dict[str, PyTree] = {}
         for tid in task_ids:
-            op, st, flag = operators[tid], states[tid], active[tid]
-            if op.is_source:
-                st2, y = jax.lax.cond(
-                    flag,
-                    lambda op=op, st=st: op.apply(st),
-                    lambda st=st, b=batch_of[tid]: (
-                        st,
-                        jnp.zeros((b, EVENT_WIDTH), jnp.float32),
-                    ),
-                )
-            else:
-                xs = [
-                    outputs[p] if p in outputs else inputs[topic_for(p)]
-                    for p in parents[tid]
-                ]
-                x = xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
-                if op.is_sink:
-                    st2 = jax.lax.cond(
-                        flag,
-                        lambda op=op, st=st, x=x: op.apply(st, x)[0],
-                        lambda st=st: st,
-                    )
-                    y = None
-                else:
-                    # ops may change the event width (e.g. lm_embed lifts
-                    # (B, 8) → (B, d)); the paused branch must emit zeros of
-                    # the op's *output* shape, not the input's.
-                    _, y_abs = jax.eval_shape(op.apply, st, x)
+            with jax.named_scope(task_type[tid]):
+                op, st, flag = operators[tid], states[tid], active[tid]
+                if op.is_source:
                     st2, y = jax.lax.cond(
                         flag,
-                        lambda op=op, st=st, x=x: op.apply(st, x),
-                        lambda st=st, y_abs=y_abs: (
+                        lambda op=op, st=st: op.apply(st),
+                        lambda st=st, b=batch_of[tid]: (
                             st,
-                            jnp.zeros(y_abs.shape, y_abs.dtype),
+                            jnp.zeros((b, EVENT_WIDTH), jnp.float32),
                         ),
                     )
+                else:
+                    xs = [
+                        outputs[p] if p in outputs else inputs[topic_for(p)]
+                        for p in parents[tid]
+                    ]
+                    x = xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
+                    if op.is_sink:
+                        st2 = jax.lax.cond(
+                            flag,
+                            lambda op=op, st=st, x=x: op.apply(st, x)[0],
+                            lambda st=st: st,
+                        )
+                        y = None
+                    else:
+                        # ops may change the event width (e.g. lm_embed lifts
+                        # (B, 8) → (B, d)); the paused branch must emit zeros of
+                        # the op's *output* shape, not the input's.
+                        _, y_abs = jax.eval_shape(op.apply, st, x)
+                        st2, y = jax.lax.cond(
+                            flag,
+                            lambda op=op, st=st, x=x: op.apply(st, x),
+                            lambda st=st, y_abs=y_abs: (
+                                st,
+                                jnp.zeros(y_abs.shape, y_abs.dtype),
+                            ),
+                        )
             new_states[tid] = st2
             if y is not None:
                 outputs[tid] = y
@@ -229,6 +237,9 @@ def build_segment(
         # subset to the broker (runtime-switchable, no recompilation).
         return new_states, outputs
 
+    step_fn.__name__ = step_fn.__qualname__ = program_name(
+        structural_signature(spec, dataflow)
+    )
     if cache is not None:
         # Compiled-segment reuse: step through the cache's canonical jitted
         # callable (adapter-renamed per call). Structurally identical

@@ -60,6 +60,18 @@ class ShardedBackend(PlacedBackendMixin, InProcessJitBackend):
             raise ValueError("ShardedBackend needs at least one device")
         self._init_placement(placement, ewma_decay=ewma_decay)
 
+    def _mint_instruments(self) -> None:
+        super()._mint_instruments()
+        m = self.metrics
+        self._m_xchip_fetches = m.counter(
+            "repro_transport_cross_chip_fetches_total",
+            "boundary batches fetched from another chip than the consumer's",
+        )
+        self._m_xchip_bytes = m.counter(
+            "repro_transport_cross_chip_bytes_total",
+            "bytes of the boundary batches fetched from another chip",
+        )
+
     # -- placement hooks (PlacedBackendMixin) -----------------------------------
     def _n_slots(self) -> int:
         return len(self.devices)
@@ -89,10 +101,14 @@ class ShardedBackend(PlacedBackendMixin, InProcessJitBackend):
         transfer per cross-segment hop); per-topic synchronization comes
         from the base fetch (concurrent steps sync on producers only)."""
         dev = self.devices[self.device_of[seg.spec.name]]
-        return {
-            t: jax.device_put(batch, dev)
-            for t, batch in super()._fetch_inputs(seg, copy=copy).items()
-        }
+        out = {}
+        for t, batch in super()._fetch_inputs(seg, copy=copy).items():
+            leaves = jax.tree_util.tree_leaves(batch)
+            if any(_elsewhere(x, dev) for x in leaves):
+                self._m_xchip_fetches.inc()
+                self._m_xchip_bytes.inc(sum(x.nbytes for x in leaves))
+            out[t] = jax.device_put(batch, dev)
+        return out
 
     def _gather_inputs(self, seg: Segment):
         # No view path here: device_put on the host platform may alias
@@ -122,3 +138,10 @@ class ShardedBackend(PlacedBackendMixin, InProcessJitBackend):
         if getattr(self.policy, "name", ""):
             cfg["placement"] = self.policy.name
         return cfg
+
+
+def _elsewhere(leaf: Any, dev: Any) -> bool:
+    """Whether a fetched leaf sits on another device than ``dev``; host
+    arrays (the shm and tcp transports deliver numpy) sit on none."""
+    devices = getattr(leaf, "devices", None)
+    return devices is not None and devices() != {dev}

@@ -461,11 +461,7 @@ class StreamSystem:
             # would have stepped — modelled at this step's per-live-task
             # cost. Accumulated here (where the step happens), mirrored out
             # by the /metrics scrape.
-            self.backend.metrics.counter(
-                "repro_reuse_core_steps_avoided_total",
-                "modelled core-equivalent step cost avoided by reuse, "
-                "accumulated per step (per-live-task cost × tasks saved)",
-            ).inc(report.cost / report.live_tasks * saved)
+            self._m_core_avoided.inc(report.cost / report.live_tasks * saved)
         if self._autoscaler is not None:
             self._autoscaler.observe(report)
         if (
@@ -788,6 +784,12 @@ class StreamSystem:
             return
         registry.add_collector(self._collect_obs)
         self._obs_registry = registry
+        # minted once per registry so step() does no name lookup
+        self._m_core_avoided = registry.counter(
+            "repro_reuse_core_steps_avoided_total",
+            "modelled core-equivalent step cost avoided by reuse, "
+            "accumulated per step (per-live-task cost × tasks saved)",
+        )
 
     def _collect_obs(self) -> None:
         """Mirror transport / compile-cache / reuse state into the registry.

@@ -26,6 +26,48 @@ def _run(code: str, devices: int = 4, timeout: int = 900):
     )
 
 
+def test_sharded_cross_chip_counters_match_placement():
+    """On four devices the sharded backend counts one cross-chip fetch per
+    boundary topic whose producer sits on another device than its
+    consumer, and the bytes of those batches, once per step."""
+    code = """
+import sys
+sys.path.insert(0, "tests")
+from helpers import chain_df, fig1
+from repro.runtime.broker import topic_for
+from repro.runtime.system import StreamSystem
+
+s = StreamSystem(strategy="signature", backend="sharded", base_batch=16, step_mode="sync")
+# a fifth segment, round-robin back on the first device, reads A's kalman
+# there: one boundary fetch that crosses no chip
+E = chain_df("E", "urban", [("parse", {}), ("kalman", {"q": 0.1}), ("avg", {})], "store_e")
+for df in (*fig1(), E):
+    s.submit(df.copy())
+be, steps = s.backend, 3
+s.run(steps)
+hops = local = nbytes = 0
+batches = be.transport.topics()
+for name, seg in be.segments.items():
+    inside = set(seg.spec.task_ids)
+    for p in {p for t in seg.spec.task_ids for p in seg.spec.parents[t] if p not in inside}:
+        if be.device_of[be._owner(p)] != be.device_of[name]:
+            hops += 1
+            nbytes += batches[topic_for(p)].nbytes
+        else:
+            local += 1
+assert hops > 0 and local > 0, (be.device_of, hops, local)
+snap = s.metrics_snapshot()
+fetches = sum(v for _, v in snap["repro_transport_cross_chip_fetches_total"]["values"])
+moved = sum(v for _, v in snap["repro_transport_cross_chip_bytes_total"]["values"])
+assert fetches == steps * hops, (fetches, steps, hops)
+assert moved == steps * nbytes, (moved, steps, nbytes)
+print("OK", hops, nbytes)
+"""
+    r = _run(code)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
 def test_moe_ep_matches_dense_dispatch():
     """Expert-parallel shard_map MoE ≡ GSPMD scatter dispatch (no drops)."""
     code = """

@@ -113,6 +113,29 @@ class TestCompileCache:
         assert list(d[0].values()) == list(d[1].values()) == list(d[2].values())
         system.close()
 
+    def test_segment_program_names_its_structure_and_task_types(self):
+        """A riot segment's program is ``jit_segment_<signature>`` and each
+        task's ops sit under a named scope of its task type."""
+        import re
+        from types import SimpleNamespace
+
+        from repro.runtime.compile_cache import program_name, structural_signature
+        from repro.runtime.system import StreamSystem
+        from repro.workloads.riot import riot_workload
+
+        df = riot_workload()[0]
+        system = StreamSystem(strategy="signature", backend="inprocess", base_batch=16)
+        system.submit(df)
+        be = system.backend
+        (seg,) = be.segments.values()
+        name = program_name(structural_signature(seg.spec, SimpleNamespace(tasks=be.task_defs)))
+        lowered = seg.step_fn.lower(seg.states, seg.active, be._fetch_inputs(seg))
+        text = lowered.as_text(debug_info=True)
+        scopes = set(re.findall(rf"jit\({name}\)/(\w+)/", text))
+        assert scopes == {t.type for t in df.tasks.values()}
+        assert f"jit_{name}" in lowered.as_text()
+        system.close()
+
     def test_config_change_misses(self):
         from repro.runtime.system import StreamSystem
 

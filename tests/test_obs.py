@@ -5,7 +5,8 @@ Five layers:
     snapshot/merge (the multiproc aggregation path), the null twin,
     collectors, Prometheus render/parse round-trips;
   * tracing: span recording, stride sampling, ring-buffer bounds, error
-    spans, Chrome trace-event export;
+    spans, Chrome trace-event export, the bridge onto the profiler's
+    clock (host annotations nested as a step runs them);
   * system wiring: merge/unmerge/step spans, reuse-savings metrics
     cross-checked against manager/ledger ground truth, ``configure_obs``
     registry swaps, the canonical ``segment_latency_ms()`` accessor vs the
@@ -23,6 +24,9 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
+from contextlib import contextmanager
 
 import pytest
 
@@ -43,6 +47,7 @@ from repro.runtime.system import StreamSystem
 from helpers import fig1
 
 STEP_MODE = os.environ.get("REPRO_TEST_STEP_MODE") or "sync"
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def sample(families, name, **labels):
@@ -218,6 +223,80 @@ class TestTracer:
         assert any(e["ph"] == "X" for e in loaded["traceEvents"])
 
 
+class TestProfilerBridge:
+    """``Tracer.annotate``: each recorded span also opens a host annotation
+    (the jit backends install ``jax.profiler.TraceAnnotation``)."""
+
+    @staticmethod
+    def _recording_tracer(**kw):
+        calls = []
+
+        @contextmanager
+        def annotate(name, **args):
+            calls.append(("open", name, args))
+            yield
+            calls.append(("close", name))
+
+        t = Tracer(**kw)
+        t.annotate = annotate
+        return t, calls
+
+    def test_enabled_span_annotates_repro_name(self):
+        t, calls = self._recording_tracer(enabled=True)
+        with t.span("fetch", "transport", segment="seg1", topics=2):
+            assert calls == [("open", "repro.fetch", {})]
+        assert calls[-1] == ("close", "repro.fetch")
+        assert [s["name"] for s in t.drain()] == ["fetch"]  # ring buffer as before
+
+    def test_segment_span_annotates_fixed_name_with_segment_arg(self):
+        t, calls = self._recording_tracer(enabled=True)
+        with t.span("seg17", "segment", step=4):
+            pass
+        assert calls == [("open", "repro.segment", {"segment": "seg17"}),
+                         ("close", "repro.segment")]
+
+    def test_disabled_span_annotates_nothing(self):
+        t, calls = self._recording_tracer(enabled=False)
+        with t.span("step"):
+            pass
+        assert calls == [] and t.drain() == []
+
+    def test_sampled_out_span_annotates_nothing(self):
+        t, calls = self._recording_tracer(enabled=True, sample_stride=2)
+        for _ in range(4):
+            with t.span("dispatch", "device"):
+                pass
+        assert [c for c in calls if c[0] == "open"] == [("open", "repro.dispatch", {})] * 2
+
+    def test_annotation_closes_when_the_span_raises(self):
+        t, calls = self._recording_tracer(enabled=True)
+        with pytest.raises(RuntimeError):
+            with t.span("wait", "device"):
+                raise RuntimeError("x")
+        assert calls == [("open", "repro.wait", {}), ("close", "repro.wait")]
+
+    def test_obs_imports_without_jax(self):
+        code = ("import sys, repro.obs; "
+                "t = repro.obs.Tracer(enabled=True); "
+                "assert t.annotate is None and 'jax' not in sys.modules")
+        env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+        r = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+
+    def test_jit_backends_install_the_profiler_annotation(self):
+        import jax
+
+        jit = _fig1_system(backend="inprocess", base_batch=8)
+        dry = _fig1_system()
+        try:
+            assert jit.backend.tracer.annotate is jax.profiler.TraceAnnotation
+            assert dry.backend.tracer.annotate is None
+        finally:
+            jit.close()
+            dry.close()
+
+
 # -- system wiring ----------------------------------------------------------------
 
 
@@ -284,6 +363,34 @@ class TestSystemObs:
         system.configure_obs(metrics=True)  # fresh registry, collector re-wired
         assert snap_value(system.metrics_snapshot(), "repro_reuse_tasks_saved") is not None
         system.close()
+
+    def test_registry_swap_moves_the_minted_core_steps_counter(self):
+        system = _fig1_system()
+        system.step()
+        system.configure_obs(metrics=False)
+        system.step()  # the null registry's counter takes the increment
+        system.configure_obs(metrics=True)
+        assert snap_value(system.metrics_snapshot(),
+                          "repro_reuse_core_steps_avoided_total") is None
+        system.step()
+        assert snap_value(system.metrics_snapshot(),
+                          "repro_reuse_core_steps_avoided_total") > 0
+        system.close()
+
+    @pytest.mark.parametrize("step_mode", ["sync", "concurrent"])
+    def test_disabled_tracer_opens_no_span_while_stepping(self, step_mode, monkeypatch):
+        """Every hot-path span site (step, wave_dispatch, segment, fetch,
+        dispatch, wait, publish, account) is guarded by ``tracer.enabled``."""
+        system = _fig1_system(backend="inprocess", base_batch=8, step_mode=step_mode)
+
+        def no_span(*a, **k):
+            raise AssertionError(f"span {a[:1]} built with tracing off")
+
+        monkeypatch.setattr(system.backend.tracer, "span", no_span)
+        try:
+            system.run(2)
+        finally:
+            system.close()
 
     def test_segment_latency_accessor_matches_report_history(self):
         """Satellite: segment_latency_ms() is THE accessor — its digest must
@@ -403,6 +510,64 @@ class TestReportHistoryCheckpoint:
             assert restored.backend.reports[-1].step > want[-1][0]
         finally:
             restored.close()
+
+
+class TestProfilerTrace:
+    def test_step_phases_nest_on_the_profiler_clock(self, tmp_path):
+        """A traced ``inprocess`` step under ``jax.profiler``: the program's
+        spans appear as host annotations, nested as the step runs them."""
+        import jax
+        from jax.profiler import ProfileData
+
+        system = _fig1_system(backend="inprocess", base_batch=8, step_mode="sync")
+        try:
+            system.step()  # compile outside the trace
+            system.configure_obs(trace=True)
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                system.run(2)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            system.close()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        lines = [[(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns) for ev in line.events]
+                 for plane in ProfileData.from_file(str(path)).planes for line in plane.lines]
+        lines = [[ev for ev in line if ev[0].startswith("repro.")] for line in lines]
+        (events,) = [line for line in lines if line]  # one host thread steps
+
+        def inside(child, parent_name):
+            return any(p[0] == parent_name and p[1] <= child[1] and child[2] <= p[2]
+                       for p in events)
+
+        names = [ev[0] for ev in events]
+        assert names.count("repro.step") == 2
+        assert names.count("repro.account") == 2
+        for phase in ("repro.fetch", "repro.dispatch", "repro.wait", "repro.publish"):
+            assert names.count(phase) == names.count("repro.segment") > 0
+        for ev in events:
+            if ev[0] in ("repro.segment", "repro.account"):
+                assert inside(ev, "repro.step"), ev
+            elif ev[0] != "repro.step":
+                assert inside(ev, "repro.segment"), ev
+
+
+class TestCrossChipCounters:
+    def test_one_device_moves_nothing_across_chips(self):
+        """The counters exist from the start and stay at zero while every
+        segment shares the consumer's device (the four-device case runs in
+        ``test_distributed_subprocess.py``)."""
+        system = _fig1_system(backend="sharded", base_batch=8)
+        try:
+            system.run(2)
+            snap = system.metrics_snapshot()
+            for name in ("repro_transport_cross_chip_fetches_total",
+                         "repro_transport_cross_chip_bytes_total"):
+                assert snap[name]["kind"] == "counter"
+                assert snap_value(snap, name) is None  # never incremented
+            assert snap_value(snap, "repro_transport_fetches_total") > 0
+        finally:
+            system.close()
 
 
 class TestMultiprocObsHarvest:
