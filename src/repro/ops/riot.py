@@ -19,6 +19,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .base import EVENT_WIDTH, Operator, register, register_fallback, stateless
 from .costs import RIOT_COSTS, parse_config, pi_cost
@@ -158,19 +159,23 @@ def bloom_filter(cfg: Dict[str, Any]) -> Operator:
 
 @register("interpolate")
 def interpolate(cfg: Dict[str, Any]) -> Operator:
-    """Replace invalid observations with the last valid value (per channel)."""
+    """Replace invalid observations with the last valid value (per channel).
+
+    Parallel over the batch: a running max of the valid rows' indices names
+    each row's last valid row, whose values are gathered (channel-major, so
+    the gather runs along the batch); rows before the batch's first valid
+    row take the carried values. Bit-identical to the row-by-row recurrence.
+    """
 
     def init_state(batch: int):
         return jnp.zeros((5,), dtype=jnp.float32)
 
     def apply(state, x):
-        def step(carry, row):
-            valid = row[FLAG] > 0.5
-            vals = jnp.where(valid, row[VAL], carry)
-            return vals, row.at[VAL].set(vals).at[FLAG].set(1.0)
-
-        new_state, y = jax.lax.scan(step, state, x)
-        return new_state, y
+        rows = jnp.arange(x.shape[0])
+        last = jax.lax.cummax(jnp.where(x[:, FLAG] > 0.5, rows, -1), axis=0)
+        vals = jnp.take(x[:, VAL].T, jnp.maximum(last, 0), axis=1, mode="clip")
+        vals = jnp.where(last >= 0, vals, state[:, None])  # (5, B)
+        return vals[:, -1], x.at[:, VAL].set(vals.T).at[:, FLAG].set(1.0)
 
     return Operator("interpolate", init_state, apply, cost_weight=RIOT_COSTS["interpolate"])
 
@@ -201,9 +206,44 @@ def annotate(cfg: Dict[str, Any]) -> Operator:
 
 # -- STATS family --------------------------------------------------------------
 
+def _delay(a, shift: int):
+    """``a`` moved ``shift`` places later along its last axis, zeros first."""
+    pad = [(0, 0)] * (a.ndim - 1) + [(shift, 0)]
+    return jnp.pad(a[..., :-shift], pad)
+
+
+def _moebius_prefix(q: float, r: float, n: int) -> np.ndarray:
+    """Normalised powers M^1..M^n of the Kalman variance map's matrix.
+
+    One row maps the variance p to r(p+q)/(p+q+r), the Moebius map of
+    M = [[r, qr], [1, q+r]], so p after t rows is M^t applied to p. The
+    powers depend only on (q, r, n): a prefix product by doubling, in
+    float64 at trace time, each normalised by its largest entry (raw powers
+    overflow float32 within a few hundred rows). Returns (4, n): the
+    entries 00, 01, 10, 11 of each power.
+    """
+    pw = np.repeat(np.array([[r], [q * r], [1.0], [q + r]]), n, axis=1)
+    shift = 1
+    while shift < n:  # pw[t] <- pw[t] @ pw[t - shift]
+        u, v = pw[:, :-shift], pw[:, shift:]
+        c = np.stack([v[0] * u[0] + v[1] * u[2], v[0] * u[1] + v[1] * u[3],
+                      v[2] * u[0] + v[3] * u[2], v[2] * u[1] + v[3] * u[3]])
+        pw[:, shift:] = c / np.abs(c).max(axis=0)
+        shift *= 2
+    return pw
+
+
 @register("kalman")
 def kalman(cfg: Dict[str, Any]) -> Operator:
-    """Scalar Kalman filter per observation channel (real recurrence)."""
+    """Scalar Kalman filter per observation channel (real recurrence).
+
+    Parallel over the batch, channel-major: the variance before each row is
+    a Moebius map of the carried variance by a power of a fixed 2x2 matrix
+    (:func:`_moebius_prefix`); given the gains k, the estimate follows the
+    affine maps x -> x - k x + k z, composed by a doubling prefix scan. A
+    composed map is kept as (c, b) for x -> x - c x + b: c = 1 - prod(1-k)
+    loses no precision where the product is near 1 (small gains).
+    """
     q = float(cfg.get("q", 0.1))  # process noise
     r = float(cfg.get("r", 1.0))  # measurement noise
 
@@ -211,16 +251,21 @@ def kalman(cfg: Dict[str, Any]) -> Operator:
         return {"x": jnp.zeros((5,)), "p": jnp.ones((5,))}
 
     def apply(state, x):
-        def step(carry, row):
-            xe, p = carry
-            p_pred = p + q
-            k = p_pred / (p_pred + r)
-            xe2 = xe + k * (row[VAL] - xe)
-            p2 = (1.0 - k) * p_pred
-            return (xe2, p2), row.at[VAL].set(xe2)
-
-        (xe, p), y = jax.lax.scan(step, (state["x"], state["p"]), x)
-        return {"x": xe, "p": p}, y
+        n = x.shape[0]
+        pw = jnp.asarray(_moebius_prefix(q, r, n), x.dtype)
+        p0 = state["p"][:, None]
+        after = (pw[0] * p0 + pw[1]) / (pw[2] * p0 + pw[3])  # (5, B)
+        p_pred = jnp.concatenate([p0, after[:, :-1]], axis=1) + q
+        c = p_pred / (p_pred + r)  # the gains: one row's map
+        b = c * x[:, VAL].T
+        shift = 1
+        while shift < n:  # compose each map after the one ending `shift` rows before
+            uc, ub = _delay(c, shift), _delay(b, shift)  # zeros: the identity map
+            c, b = uc + c * (1.0 - uc), ub - c * ub + b
+            shift *= 2
+        x0 = state["x"][:, None]
+        xe = x0 - c * x0 + b
+        return {"x": xe[:, -1], "p": after[:, -1]}, x.at[:, VAL].set(xe.T)
 
     return Operator("kalman", init_state, apply, cost_weight=RIOT_COSTS["kalman"])
 

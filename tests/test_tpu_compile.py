@@ -102,7 +102,7 @@ def test_merged_riot_etl_segment_compiles_for_v5e(one_chip, no_persistent_cache)
         text = _segment_text(first, one_chip)
     finally:
         session.close()
-    assert "while" in text  # interpolate and kalman scan over the batch
+    assert "while" not in text  # interpolate and kalman are parallel prefixes, no row loop
 
 
 def test_fused_rmsnorm_segment_compiles_for_v5e(one_chip, no_persistent_cache,
