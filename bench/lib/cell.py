@@ -1,15 +1,18 @@
 """Finds a cell's pieces by name: ``BENCHMARK.json`` names the pair
 (configuration, traffic); the configuration, its collection, the traffic mix,
-the correctness limits and each metric's reader sit in files of their own
-under ``bench/``, so a later cell, configuration or metric is added by adding
-files and entries, never by editing one that is there."""
+the correctness limits, each metric's reader and, where a configuration's
+task types need semantics the plain reference lacks, its own reference
+module sit in files of their own under ``bench/``, so a later cell,
+configuration or metric is added by adding files and entries, never by
+editing one that is there."""
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List
+from types import ModuleType
+from typing import Any, Callable, Dict, List, Optional
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
@@ -68,10 +71,25 @@ def make_cell(name: str, config_name: str, traffic: str, chips: int,
     )
 
 
-def reader(metric: str) -> Callable[[Any], Any]:
-    """``read(ctx)`` of ``bench/metrics/<metric>.py``."""
-    path = os.path.join(BENCH, "metrics", f"{metric}.py")
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
+def _module(name: str, path: str) -> ModuleType:
+    mod_spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str) -> Callable[[Any], Any]:
+    """``read(ctx)`` of ``bench/metrics/<metric>.py``."""
+    return _module(f"bench_metric_{metric}", os.path.join(BENCH, "metrics", f"{metric}.py")).read
+
+
+def reference_module(config: Dict[str, Any]) -> Optional[ModuleType]:
+    """The configuration's own reference semantics: the module its
+    ``"reference"`` key names (a path under ``bench/``, from the checkout's
+    root), or None where it has none."""
+    path = config.get("reference")
+    if path is None:
+        return None
+    if not os.path.normpath(path).startswith("bench" + os.sep):
+        raise ValueError(f"reference {path!r} is not under bench/")
+    return _module(f"bench_reference_{config['name']}", os.path.join(ROOT, path))

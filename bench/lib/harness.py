@@ -33,7 +33,7 @@ from types import SimpleNamespace
 from typing import Any, Dict, List, Tuple
 
 from . import check, devtrace, program, reference
-from .cell import ROOT, Cell, reader
+from .cell import ROOT, Cell, reader, reference_module
 from .clock import CompileClock
 from .traffic import make_plan
 
@@ -194,6 +194,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     import jax
 
     batch = batch or int(cell.config["batch"])
+    extra = reference_module(cell.config)  # a bad file fails here, before set-up
     clock = clock or CompileClock()
     plan = make_plan(cell.traffic, [f["name"] for f in cell.collection], seconds)
     run = Run(cell, seed, batch, devices)
@@ -230,15 +231,15 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
 
     flows = {f["name"]: reference.Flow(f["name"], f["source"], [tuple(s) for s in f["steps"]], f["sink"])
              for f in cell.collection}
+    semantics = dict(fallback=cell.config.get("task_fallback"), device=devices[0], extra=extra)
     t_ref = time.perf_counter()
     want = reference.run_reference(flows, log, steps, batch, starts, dtype=cell.config["dtype"],
-                                   fallback=cell.config.get("task_fallback"), device=devices[0])
+                                   **semantics)
     ref_s = time.perf_counter() - t_ref
     ok, checks = check.judge(check.readings(got, want), cell.limits)
     control_readings = {}
     for dtype in controls:
-        low = reference.run_reference(flows, log, steps, batch, starts, dtype=dtype,
-                                      fallback=cell.config.get("task_fallback"), device=devices[0])
+        low = reference.run_reference(flows, log, steps, batch, starts, dtype=dtype, **semantics)
         control_readings[dtype] = check.readings(low, want)
     dev = devices[0]
     result: Dict[str, Any] = {

@@ -23,8 +23,17 @@ Semantics (the configuration's guarantees):
 The stream is processed in blocks of ``block`` steps: for each running source
 the chains below it are walked depth first, one jitted call per task and
 block, so the reference never holds more than one block per chain level.
-Within a batch the recurrences (interpolate, kalman) use parallel forms, so
-the reference is much cheaper than the program's row-by-row scans.
+Within a batch the recurrences (interpolate, kalman) run as parallel prefix
+scans, written here from their row-by-row definitions.
+
+A configuration whose task or source types this module does not know brings
+their semantics in a module of its own (``extra``: its ``"reference"`` file,
+which imports nothing of ``repro`` either). It maps each new task type to
+``factory(cfg)`` with ``make_task``'s contract (``TASKS``) and, optionally,
+each new source type (the part before any ':') to ``factory(batch)`` with
+``make_source``'s (``SOURCES``). It may add types, never redefine one here:
+the chain walk, reuse by prefix, the sink and the event layout stay this
+module's.
 """
 from __future__ import annotations
 
@@ -327,11 +336,25 @@ def _sink_apply(st, x):
 
 # -- block programs: one task over K steps, masked by the steps it runs -------------
 
+def _extension(extra) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+    """``extra``'s (TASKS, SOURCES); raises where it redefines a type this
+    module knows."""
+    if extra is None:
+        return {}, {}
+    tasks, sources = dict(extra.TASKS), dict(getattr(extra, "SOURCES", {}))
+    clash = sorted(set(tasks) & set(KNOWN_TASKS)) + sorted(
+        t for t in sources if t.split(":")[0] in PROFILES)
+    if clash:
+        raise ValueError(f"the configuration's reference redefines {clash}")
+    return tasks, sources
+
+
 class Programs:
     """Jitted block programs, cached by task definition and dtype."""
 
-    def __init__(self, batch: int, dtype, fallback: Optional[str]):
+    def __init__(self, batch: int, dtype, fallback: Optional[str], extra=None):
         self.batch, self.dtype, self.fallback = batch, dtype, fallback
+        self.extra_tasks, self.extra_sources = _extension(extra)
         self._cache: Dict[Any, Any] = {}
 
     def source(self, typ: str):
@@ -340,19 +363,23 @@ class Programs:
         if key not in self._cache:
             import jax
 
-            emit, dtype = make_source(typ, self.batch), self.dtype
+            factory = self.extra_sources.get(typ.split(":")[0])
+            emit = factory(self.batch) if factory else make_source(typ, self.batch)
+            dtype = self.dtype
             self._cache[key] = jax.jit(lambda counters: jax.vmap(lambda c: emit(c, dtype))(counters))
         return self._cache[key]
 
     def task(self, typ: str, cfg_key: str):
-        if typ not in KNOWN_TASKS and self.fallback is not None:
+        if typ not in KNOWN_TASKS and typ not in self.extra_tasks and self.fallback is not None:
             typ = self.fallback  # one program for every task the fallback covers
         key = ("task", typ, cfg_key)
         if key not in self._cache:
             import jax
             import jax.numpy as jnp
 
-            init, apply = make_task(typ, json.loads(cfg_key))
+            factory = self.extra_tasks.get(typ)
+            cfg = json.loads(cfg_key)
+            init, apply = factory(cfg) if factory else make_task(typ, cfg)
             if init is None:
                 run = jax.jit(lambda xs: jax.vmap(lambda x: apply((), x)[1])(xs))
                 self._cache[key] = (None, run)
@@ -383,12 +410,13 @@ class Programs:
 def run_reference(flows: Dict[str, Flow], log: Sequence[Tuple[int, str, str]],
                   steps: int, batch: int, counter_start: Dict[Tuple[str, int], int],
                   dtype="float32", block: int = 16, fallback: Optional[str] = None,
-                  device=None) -> Dict[str, Dict[str, Any]]:
+                  device=None, extra=None) -> Dict[str, Dict[str, Any]]:
     """Sink contents of every dataflow running after ``steps`` steps.
 
     ``counter_start`` maps (source type, step the source started) to its
-    first counter where it was set. Returns ``{dataflow: {"count", "checksum",
-    "last"}}`` with numpy values.
+    first counter where it was set; ``extra`` is the configuration's own
+    reference module, if it has one. Returns ``{dataflow: {"count",
+    "checksum", "last"}}`` with numpy values.
     """
     import jax
     import jax.numpy as jnp
@@ -409,7 +437,7 @@ def run_reference(flows: Dict[str, Flow], log: Sequence[Tuple[int, str, str]],
                     parent.children.append(nodes[key])
             parent = nodes[key]
     roots = [n for k, n in nodes.items() if len(k) == 1]
-    progs = Programs(batch, dtype, fallback)
+    progs = Programs(batch, dtype, fallback, extra)
     out: Dict[str, Dict[str, Any]] = {}
     with jax.default_device(device) if device is not None else contextlib.nullcontext():
         states: Dict[Tuple, Any] = {}

@@ -1,5 +1,7 @@
-"""Device time per step in XLA ``while`` loops (the row-by-row scans and the
-pi loops), from the profiler trace (mean over the chips used)."""
+"""Device time per step in XLA ``while`` loops, from the profiler trace (mean
+over the chips used): the ``pi`` tasks' loops (``opmw35``), the only loops
+on the benchmarked path (the ``riot21`` ops run none). Nothing where no loop
+runs."""
 
 
 def read(ctx):

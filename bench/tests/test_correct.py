@@ -36,6 +36,11 @@ CASES = [
     ("opmw35.churn", "half_batch", (), False),
     ("opmw35.churn", "altered", (), False),
     ("opmw35.churn", "control", (), False),
+    ("riot21.churn", "none", (), True),
+    ("riot21.churn", "state_unchanged", (), False),
+    ("riot21.churn", "half_batch", (), False),
+    ("riot21.churn", "altered", (), False),
+    ("riot21.churn", "control", (), False),
     ("riot21_x4.steady", "none", (), True),
     ("riot21_x4.steady", "no_exchange", (), False),
     # The churn cell fixes its draws of the swaps; the check holds on others.
