@@ -1,8 +1,9 @@
 """Task-type registry: ⟨type, config⟩ → executable JAX operator.
 
 Real RIoT-style IoT task logic (:mod:`repro.ops.riot`), deterministic
-synthetic sources (:mod:`repro.ops.sources`), digest sinks
-(:mod:`repro.ops.sinks`), and the OPMW π fallback. Model-block operators
+synthetic sources (:mod:`repro.ops.sources`), the NEXmark generator and
+queries (:mod:`repro.ops.nexmark`), digest sinks (:mod:`repro.ops.sinks`),
+and the OPMW π fallback. Model-block operators
 (embed / layer-group / head for multi-tenant LM serving) are registered by
 :mod:`repro.serve.model_ops` when imported.
 
@@ -59,12 +60,14 @@ __all__ = [
 
 def operator_for_task(task, batch: int = 32):
     """Instantiate the JAX operator for a concrete task (source/sink aware)."""
-    from . import riot  # noqa: F401 — populates the registry (imports JAX)
+    from . import nexmark, riot  # noqa: F401 — populate the registry (import JAX)
     from .base import make_operator
     from .sinks import make_sink
     from .sources import make_source
 
     if task.is_source:
+        if task.type.split(":")[0] == "nexmark":
+            return nexmark.make_source(task.type, batch=batch)
         return make_source(task.type, batch=batch)
     if task.is_sink:
         return make_sink(task.type)
@@ -73,7 +76,7 @@ def operator_for_task(task, batch: int = 32):
 
 def __getattr__(name: str):
     if name in _BASE_NAMES:
-        from . import riot  # noqa: F401 — registry side effects before use
+        from . import nexmark, riot  # noqa: F401 — registry side effects before use
         module = importlib.import_module(f"{__name__}.base")
     elif name == "make_sink":
         module = importlib.import_module(f"{__name__}.sinks")

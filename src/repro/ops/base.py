@@ -51,6 +51,9 @@ class Operator:
     cost_weight: float = 1.0
     is_source: bool = False
     is_sink: bool = False
+    # a keyed event-time window task (repro.ops.nexmark): the backend counts
+    # the rows fed to it and the device bytes of its state
+    window: bool = False
 
 
 OperatorFactory = Callable[[Dict[str, Any]], Operator]

@@ -2,7 +2,7 @@
 
 ``cost_weight`` is the relative per-event CPU cost used by the resource
 accounting that reproduces the paper's Fig. 3 (cumulative cores). The jit
-operator factories (:mod:`repro.ops.riot`, :mod:`repro.ops.sources`,
+operator factories (:mod:`repro.ops.riot`, :mod:`repro.ops.nexmark`, :mod:`repro.ops.sources`,
 :mod:`repro.ops.sinks`, :mod:`repro.serve.model_ops`) read their weights
 from here, and :class:`repro.runtime.dryrun.DryRunBackend` evaluates the
 same weights **without** instantiating any JAX operator — which is what
@@ -43,6 +43,17 @@ RIOT_COSTS: Dict[str, float] = {
     "dtree": 1.3,
     "sliding_linreg": 2.2,
     "error_estimate": 0.4,
+}
+
+# NEXmark queries (repro.ops.nexmark): maps and filters cost like ETL tasks,
+# the keyed windows (Q5 counts per auction, Q7 a max with ties) like the
+# windowed statistics.
+NEXMARK_COSTS: Dict[str, float] = {
+    "nx_bids": 0.3,
+    "nx_convert": 0.4,
+    "nx_select": 0.4,
+    "nx_hot_items": 2.0,
+    "nx_highest_bid": 1.2,
 }
 
 # LM-pipeline stages (multi-tenant reuse serving).
@@ -98,6 +109,8 @@ def cost_weight_for(
         return SINK_COST
     if type_name in RIOT_COSTS:
         return RIOT_COSTS[type_name]
+    if type_name in NEXMARK_COSTS:
+        return NEXMARK_COSTS[type_name]
     cfg = parse_config(config)
     if type_name == "lm_embed":
         return LM_EMBED_COST

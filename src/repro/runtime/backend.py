@@ -35,7 +35,7 @@ import importlib
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Set, Tuple, Type, Union
+from typing import Any, Callable, Dict, Iterable, List, Optional, Set, Tuple, Type, Union
 
 from repro.core.graph import Dataflow, Task
 from repro.obs import NULL_REGISTRY, MetricsRegistry, Tracer
@@ -140,6 +140,15 @@ class BackendSnapshot:
     paused_tasks: int
     cost: float
     device_of: Dict[str, Any] = field(default_factory=dict)  # sharded only
+
+
+def _nbytes(tree: Any) -> int:
+    """Bytes of a state pytree's array leaves (dicts, lists and tuples)."""
+    if isinstance(tree, dict):
+        return sum(_nbytes(v) for v in tree.values())
+    if isinstance(tree, (list, tuple)):
+        return sum(_nbytes(v) for v in tree)
+    return int(getattr(tree, "nbytes", 0))
 
 
 def compute_batches(
@@ -249,6 +258,11 @@ class ExecutionBackend:
         # and a span tracer, disabled until configure_obs(trace=True)
         self.metrics: MetricsRegistry = MetricsRegistry()
         self.tracer = Tracer(enabled=False)
+        # keyed window tasks (Operator.window): task id -> (type, state
+        # bytes, the state's ``rows`` at the last harvest); a step adds
+        # nothing here
+        self._window_tasks: Dict[str, Tuple[str, int, int]] = {}
+        self._window_types: Set[str] = set()
         self._mint_instruments()
 
     def _mint_instruments(self) -> None:
@@ -266,6 +280,14 @@ class ExecutionBackend:
         self._m_cost = m.gauge(
             "repro_cost_cores", "core-equivalents consumed by the last step"
         )
+        self._m_window_rows = m.counter(
+            "repro_ops_window_rows_total", "valid rows fed to keyed window tasks, by type"
+        )
+        self._m_window_bytes = m.gauge(
+            "repro_ops_window_state_bytes", "device bytes of deployed window task state, by type"
+        )
+        m.add_collector(self._harvest_windows)
+        self._sum_windows()
 
     def configure_obs(
         self,
@@ -388,7 +410,41 @@ class ExecutionBackend:
         deps.discard(spec.name)
         self.seg_deps[spec.name] = deps
         self._waves_cache = None
+        operators = getattr(seg, "operators", None) or {}
+        windows = [t for t in spec.task_ids if getattr(operators.get(t), "window", False)]
+        for tid in windows:
+            state = seg.states[tid]  # carried over, its ``rows`` already counted
+            self._window_tasks[tid] = (
+                operators[tid].type, _nbytes(state), int(state["rows"])
+            )
+        if windows:
+            self._sum_windows()
         return seg
+
+    def _sum_windows(self) -> None:
+        """Set the state gauge: bytes of every deployed window task (paused
+        included) by type, 0 for a type with none left."""
+        total = dict.fromkeys(self._window_types, 0)
+        for typ, nbytes, _ in self._window_tasks.values():
+            total[typ] = total.get(typ, 0) + nbytes
+        self._window_types |= set(total)
+        for typ, nbytes in total.items():
+            self._m_window_bytes.set(nbytes, type=typ)
+
+    def _harvest_windows(
+        self, task_ids: Optional[Iterable[str]] = None, segment: Any = None
+    ) -> None:
+        """Add to ``repro_ops_window_rows_total`` the valid rows each window
+        task (all by default) counted on the device since the last harvest:
+        at every metrics snapshot (a collector) and at kill. The state's
+        int32 ``rows`` wraps, so the difference is taken mod 2**32: exact
+        while a task sees fewer than 2**32 rows between two harvests."""
+        for tid in list(self._window_tasks if task_ids is None else task_ids):
+            typ, nbytes, seen = self._window_tasks[tid]
+            seg = segment if segment is not None else self.segments[self._owner_of[tid]]
+            now = int(seg.states[tid]["rows"])
+            self._m_window_rows.inc((now - seen) % (1 << 32), type=typ)
+            self._window_tasks[tid] = (typ, nbytes, now)
 
     def kill(self, segment_name: str) -> None:
         seg = self.segments.pop(segment_name)
@@ -399,11 +455,17 @@ class ExecutionBackend:
             deps.discard(segment_name)
         self._waves_cache = None
         self._drop_streams(seg)
+        windows = [t for t in seg.spec.task_ids
+                   if t in self._window_tasks and self._owner_of.get(t) == segment_name]
+        self._harvest_windows(windows, seg)
         for tid in seg.spec.task_ids:
             self.paused.discard(tid)
             if self._owner_of.get(tid) == segment_name:
                 del self._owner_of[tid]
                 self.task_defs.pop(tid, None)
+                self._window_tasks.pop(tid, None)
+        if windows:
+            self._sum_windows()
 
     # -- control signals (paper §4.3 control topic) -----------------------------
     def forward(self, task_id: str) -> None:

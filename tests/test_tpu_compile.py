@@ -122,3 +122,32 @@ def test_fused_rmsnorm_segment_compiles_for_v5e(one_chip, no_persistent_cache,
     finally:
         session.close()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "steps,kernel",
+    [
+        ([("nx_bids", {}), ("nx_select", {"modulus": 123}), ("nx_hot_items", {})], "window_count"),
+        ([("nx_bids", {}), ("nx_convert", {}), ("nx_highest_bid", {})], None),
+    ],
+    ids=["q5_over_q2", "q7_over_q1"],
+)
+def test_nexmark_segment_compiles_for_v5e(steps, kernel, one_chip, no_persistent_cache,
+                                          pallas_backend):
+    """A NEXmark dataflow's segment (generator, queries, keyed window, sink)
+    at the deployment's rate: Q5's count kernel as a Pallas call, Q7 with
+    no Pallas call (its maximum is XLA's reduce)."""
+    df = flow("nx").source("nexmark")
+    for typ, cfg in steps:
+        df.then(typ, **cfg)
+    session = ReuseSession(strategy="signature", execute=True, base_batch=BATCH)
+    try:
+        session.submit(df.sink("store").build())
+        (seg,) = session._system.backend.segments.values()
+        text = _segment_text(seg, one_chip)
+    finally:
+        session.close()
+    if kernel is None:
+        assert "tpu_custom_call" not in text
+    else:
+        assert "tpu_custom_call" in text and f"%{kernel}" in text
